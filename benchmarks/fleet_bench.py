@@ -56,6 +56,7 @@ from repro.core.agent import run_online_ddpg_python, run_online_fleet
 from repro.core.ddpg import DDPGConfig
 from repro.dsdps import SchedulingEnv, apps, scenarios
 from repro.dsdps.apps import default_workload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_fleet_mesh
 
 DEFAULT_JSON = pathlib.Path(__file__).resolve().parents[1] / "artifacts" / \
@@ -682,6 +683,13 @@ def main() -> None:
     ap.add_argument("--json", default=str(DEFAULT_JSON),
                     help="benchmark JSON artifact path ('' disables)")
     args = ap.parse_args()
+    if args.multihost and jax.default_backend() != "cpu":
+        # the sweep's workers are forced onto JAX_PLATFORMS=cpu with
+        # emulated host devices, and this process would hold the chip:
+        # its rows would mix CPU emulation with chip timings
+        ap.error("--multihost emulates hosts on the CPU (workers run with "
+                 "JAX_PLATFORMS=cpu and forced host devices); run it with "
+                 f"JAX_PLATFORMS=cpu, not on {jax.default_backend()}")
     if args.multihost_worker:
         run_multihost_worker(args.fleet, args.epochs, args.app,
                              args.worker_out)
@@ -717,4 +725,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -21,6 +21,7 @@ from benchmarks.paper_common import (Budget, make_env, run_actor_critic,
                                      run_model_based)
 from repro.core import make_agent, run_online_fleet
 from repro.dsdps import SchedulingEnv, scenarios
+from repro.launch.compile_cache import enable_compile_cache
 
 ART = pathlib.Path(__file__).resolve().parents[1] / "artifacts" / "paper"
 
@@ -91,4 +92,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ import json
 import pathlib
 
 from benchmarks.paper_common import Budget, compare_all
+from repro.launch.compile_cache import enable_compile_cache
 
 ART = pathlib.Path(__file__).resolve().parents[1] / "artifacts" / "paper"
 
@@ -39,4 +40,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

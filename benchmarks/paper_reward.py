@@ -13,6 +13,7 @@ import pathlib
 import numpy as np
 
 from benchmarks.paper_common import Budget, make_env, run_actor_critic, run_dqn
+from repro.launch.compile_cache import enable_compile_cache
 
 ART = pathlib.Path(__file__).resolve().parents[1] / "artifacts" / "paper"
 
@@ -59,4 +60,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

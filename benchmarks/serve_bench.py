@@ -43,6 +43,7 @@ import numpy as np
 from repro.core import make_agent
 from repro.dsdps import SchedulingEnv, apps, scenarios
 from repro.dsdps.apps import default_workload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.control import (ControlPlane, DecisionRequest,
                                  latency_stats, single_select_program)
 
@@ -201,4 +202,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

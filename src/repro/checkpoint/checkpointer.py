@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.treepath import keystr_path
-
 
 def _flatten(state):
     leaves, treedef = jax.tree.flatten(state)
@@ -42,7 +40,7 @@ def _flatten(state):
 
 def _leaf_paths(state):
     flat = jax.tree_util.tree_flatten_with_path(state)[0]
-    return [keystr_path(kp, separator=".") for kp, _ in flat]
+    return [jax.tree_util.keystr(kp, simple=True, separator=".") for kp, _ in flat]
 
 
 class Checkpointer:

@@ -60,7 +60,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 
 from repro.core import ddpg, dqn
 from repro.core.api import Agent, make_epoch_step
@@ -212,16 +211,16 @@ def _sharded_fleet_fn(keys, states, env_states, env_params, *, env,
     (sharding.fleet.params_partition_specs) replicates broadcast-invariant
     EnvParams leaves instead of sharding them.  Lanes are independent, so
     the body needs no collectives — each device runs the vmapped scan over
-    its local lanes (check_rep stays off: no replicated outputs to
+    its local lanes (check_vma stays off: no replicated outputs to
     certify, and the scan body trips no replication rules)."""
     spec = fleet_spec(mesh)
     body = partial(_fleet_fn, env=env, agent=agent, T=T,
                    updates_per_epoch=updates_per_epoch, explore=explore,
                    params_axes=params_axes)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(spec, spec, spec, params_specs),
-                   out_specs=(spec, spec, spec, spec, spec, spec),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(spec, spec, spec, params_specs),
+                       out_specs=(spec, spec, spec, spec, spec, spec),
+                       check_vma=False)
     return fn(keys, states, env_states, env_params)
 
 

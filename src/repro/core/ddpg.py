@@ -41,8 +41,8 @@ class DDPGConfig:
     reward_scale: float = 0.25
     eps: EpsilonSchedule = EpsilonSchedule()
     # route the K-NN projection's top-2/regret reduction through the Pallas
-    # kernel (kernels/knn_topk): compiled on TPU, interpret-mode fallback on
-    # CPU — flips the select/target hot path onto the kernels layer
+    # kernel (kernels/knn_topk) — flips the select/target hot path onto the
+    # kernels layer.  The kernel compiles for TPU only.
     use_pallas_knn: bool = False
 
     @property

@@ -86,31 +86,32 @@ def knn_actions_exact(proto: np.ndarray, k: int) -> np.ndarray:
 # exact 1-NN and only feasible actions.
 #
 # ``use_pallas=True`` computes the per-row top-2/regret reduction with the
-# kernels-layer Pallas kernel (kernels/knn_topk) instead of lax.top_k —
-# compiled on TPU, interpret-mode everywhere else (automatic fallback) —
-# so the DDPG select hot path exercises the kernel.
+# kernels-layer Pallas kernel (kernels/knn_topk) instead of lax.top_k, so
+# the DDPG select hot path exercises the kernel.  The kernel is compiled
+# unless the caller asks for ``interpret=True`` — the only way to run it
+# on a backend without Mosaic (the CPU).
 # --------------------------------------------------------------------------
-def _row_top2(proto: jnp.ndarray, use_pallas: bool):
+def _row_top2(proto: jnp.ndarray, use_pallas: bool, interpret: bool):
     """(best_col [N] i32, second_col [N] i32, flip_regret [N] f32)."""
     if use_pallas:
         from repro.kernels.knn_topk import row_top2_regret
-        return row_top2_regret(
-            proto, interpret=jax.default_backend() != "tpu")
+        return row_top2_regret(proto, interpret=interpret)
     top2_vals, top2_idx = jax.lax.top_k(proto, 2)         # [N, 2]
     flip_regret = 2.0 * (top2_vals[:, 0] - top2_vals[:, 1])   # [N]
     return top2_idx[:, 0], top2_idx[:, 1], flip_regret
 
 
-@partial(jax.jit,
-         static_argnames=("k", "pair_pool", "triple_pool", "use_pallas"))
+@partial(jax.jit, static_argnames=("k", "pair_pool", "triple_pool",
+                                   "use_pallas", "interpret"))
 def knn_actions_jax(
     proto: jnp.ndarray, k: int, pair_pool: int = 8, triple_pool: int = 4,
-    use_pallas: bool = False,
+    use_pallas: bool = False, interpret: bool = False,
 ) -> jnp.ndarray:
     """[k, N, M] one-hot candidate actions, ordered by distance to proto."""
     n, m = proto.shape
     # best / 2nd-best machine per row + single-flip regret to the 2nd-best
-    best_col, second_col, flip_regret = _row_top2(proto, use_pallas)
+    best_col, second_col, flip_regret = _row_top2(proto, use_pallas,
+                                                  interpret)
 
     pool = min(max(pair_pool, triple_pool, k), n)
     cheap_cost, cheap_rows = jax.lax.top_k(-flip_regret, pool)

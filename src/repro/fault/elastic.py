@@ -28,6 +28,8 @@ import dataclasses
 
 import jax
 
+from repro.launch.mesh import auto_mesh
+
 
 @dataclasses.dataclass(frozen=True)
 class MeshPlan:
@@ -68,7 +70,7 @@ def plan_mesh(alive_devices: int, model_parallel: int = 16,
 
 
 def make_mesh(plan: MeshPlan):
-    return jax.make_mesh(plan.shape, plan.axes)
+    return auto_mesh(plan.shape, plan.axes)
 
 
 def resume_after_failure(checkpoint, env, agent, keys, states,

@@ -7,5 +7,6 @@
 The paper itself has no kernel-level contribution (it is a scheduling
 paper — DESIGN.md §3); these kernels serve the surrounding framework's
 hot spots plus the paper's optimizer inner step.  Each ships a pure-jnp
-oracle (ref.py) and is validated in interpret=True mode (this container
-is CPU-only; TPU is the target)."""
+oracle (ref.py), validated on the CPU in interpret=True mode; TPU is
+the target, and knn_topk's compile for a v5e is checked in
+tests/test_tpu_compile.py."""

@@ -50,7 +50,9 @@ from __future__ import annotations
 import argparse
 import contextlib
 import sys
+import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import init_distributed, make_fleet_mesh
 
 if "--distributed" in sys.argv:
@@ -86,7 +88,14 @@ def build_env(app: str):
     return SchedulingEnv(topo, default_workload(topo))
 
 
-def main() -> None:
+def main(argv: list[str] | None = None) -> dict:
+    """Run the launcher on ``argv`` (default: the command line).
+
+    Returns a summary for in-process callers: ``sharded_devices`` (the
+    data-axis devices the fleet ran over; 0 when it ran un-sharded),
+    ``start_epoch``, ``epochs_run``, per-lane ``final_ms`` and
+    ``round_robin_ms``, and with ``--serve`` the per-kind ``serve`` stats
+    and ``serve_s`` (wall seconds of serving)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--app", default="cq_small",
                     choices=list(apps.ALL_APPS) + ["placement", "structural"],
@@ -169,7 +178,7 @@ def main() -> None:
                          "implicit-transfer guard, jit-cache-miss sentinel, "
                          "chunk-boundary NaN/Inf sweeps "
                          "(docs/static_analysis.md)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.fleet < 1:
         ap.error("--fleet must be >= 1")
     if args.distributed:
@@ -247,7 +256,7 @@ def main() -> None:
               f"{len(lb.entries) * sum(rungs)})")
         path = lb.save(args.search_json)
         print(f"wrote {path}")
-        return
+        return {"leaderboard": str(path)}
     env_params = (scenarios.build_for(
         env, args.scenario, args.fleet,
         broadcast_invariant=args.broadcast_invariant)
@@ -315,7 +324,7 @@ def main() -> None:
         if start_epoch >= args.epochs:
             print(f"checkpoint already at epoch {start_epoch} >= "
                   f"--epochs {args.epochs}; nothing left to run")
-            return
+            return {"start_epoch": start_epoch, "epochs_run": 0}
 
     # offline pretraining only seeds a FRESH run: restored lanes already
     # carry their replay buffers and trained networks
@@ -407,6 +416,12 @@ def main() -> None:
           f"{1 - finals[best] / rrs[best]:.1%} best")
     print("best assignment (executor -> machine):",
           hist.final_assignment[best].argmax(-1).tolist())
+    summary = {"sharded_devices": (int(mesh.devices.size)
+                                   if mesh is not None else 0),
+               "start_epoch": start_epoch,
+               "epochs_run": args.epochs - start_epoch,
+               "final_ms": finals.tolist(),
+               "round_robin_ms": rrs.tolist()}
 
     if args.serve:
         # serve the TRAINED policy through the batched control plane: the
@@ -428,13 +443,18 @@ def main() -> None:
             svc.submit(r)
         print(f"\nserving {args.serve} decision requests from the trained "
               f"policy across {n_lanes} cluster(s) ...")
+        t0 = time.perf_counter()
         served = svc.run(jax.random.fold_in(key, 3))
-        for kind, stats in svc.decision_stats().items():
+        summary["serve_s"] = time.perf_counter() - t0
+        summary["serve"] = svc.decision_stats()
+        for kind, stats in summary["serve"].items():
             print(f"  {kind:13s} n={stats['n']:4d}  "
                   f"p50 {stats['p50_ms']:8.3f} ms  "
                   f"p99 {stats['p99_ms']:8.3f} ms")
         assert len(served) == args.serve
+    return summary
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

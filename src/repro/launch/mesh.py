@@ -74,6 +74,20 @@ def init_distributed(coordinator_address: str | None = None,
     return jax.process_index(), jax.process_count()
 
 
+def auto_mesh(shape, axes, devices=None):
+    """A mesh whose axes are all ``AxisType.Auto`` — the one place meshes
+    are built.  ``jax.make_mesh`` defaults to Explicit axes, which put the
+    mesh axis into every placed array's type; a carry restored onto such a
+    mesh then refuses the fleet runner's plain vmap ("should be sharded
+    the same").  Auto axes leave sharding to the compiler, as the fleet
+    and training paths expect.  ``devices`` defaults to jax's own pick."""
+    axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(tuple(shape), tuple(axes), axis_types)
+    return jax.sharding.Mesh(np.asarray(devices).reshape(tuple(shape)),
+                             tuple(axes), axis_types=axis_types)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """The accelerator training mesh, sized to the visible devices.
 
@@ -87,7 +101,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     from repro.fault.elastic import plan_mesh
     plan = plan_mesh(jax.device_count(), model_parallel=16,
                      multi_pod=multi_pod)
-    return jax.make_mesh(plan.shape, plan.axes)
+    return auto_mesh(plan.shape, plan.axes)
 
 
 def make_host_mesh():
@@ -96,7 +110,7 @@ def make_host_mesh():
     the fleet runner this is the bit-comparability anchor: a
     ``run_online_fleet(..., mesh=make_host_mesh())`` run shards nothing,
     so its lanes match the plain vmap path."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))
 
 
 def make_fleet_mesh(n_devices: int | None = None, *, spanning: bool = False):
@@ -118,5 +132,4 @@ def make_fleet_mesh(n_devices: int | None = None, *, spanning: bool = False):
     so the same code path runs everywhere."""
     devices = list(jax.devices()) if spanning else list(jax.local_devices())
     n = len(devices) if n_devices is None else int(n_devices)
-    mesh_devices = np.asarray(devices[:n]).reshape(n, 1)
-    return jax.sharding.Mesh(mesh_devices, ("data", "model"))
+    return auto_mesh((n, 1), ("data", "model"), devices=devices[:n])

@@ -30,6 +30,7 @@ import numpy as np
 from repro.core import make_agent, spaces
 from repro.dsdps import SchedulingEnv, apps, scenarios
 from repro.dsdps.apps import default_workload
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.control import ControlPlane, ControlService, DecisionRequest
 
 DEFAULT_KINDS = ("placement", "rate_control", "auto_tune")
@@ -143,4 +144,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

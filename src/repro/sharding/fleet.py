@@ -32,6 +32,7 @@ tests pin."""
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -156,6 +157,33 @@ def compaction_size(n_live: int, mesh: Mesh | None) -> int:
     return int(-(-int(n_live) // n) * n)          # ceil to a multiple of n
 
 
+def _buffer_ids(x) -> set:
+    return {(s.device.id, s.data.unsafe_buffer_pointer())
+            for s in x.addressable_shards}
+
+
+def own_buffers(read, carries):
+    """``carries`` with every leaf on device buffers of its own.
+
+    A donating call refuses a buffer that backs two of its arguments, and
+    fleet arrays often share one: an eager ``init_fleet`` returns its
+    online nets as their targets too, and an env reset passes its params'
+    arrays through.  Each carry leaf whose buffers also back a leaf of
+    ``read`` (passed to the same call, not donated) or an earlier carry
+    leaf is copied."""
+    seen = set().union(*map(_buffer_ids, jax.tree.leaves(read)))
+
+    def own(x):
+        ids = _buffer_ids(x)
+        if ids & seen:
+            x = jnp.copy(x)
+            ids = _buffer_ids(x)
+        seen.update(ids)
+        return x
+
+    return jax.tree.map(own, carries)
+
+
 def shard_fleet(mesh: Mesh, keys, states, env_states, env_params, ref):
     """Place the fleet runner's carries on ``mesh``.
 
@@ -166,8 +194,10 @@ def shard_fleet(mesh: Mesh, keys, states, env_states, env_params, ref):
     divide the data-axis device count (``shard_map`` partitions evenly).
 
     Returns ``(keys, states, env_states, env_params, params_specs)`` with
-    every array committed to its ``NamedSharding`` and ``params_specs``
-    the hashable PartitionSpec tree for the sharded program."""
+    every array committed to its ``NamedSharding``, every carry leaf on
+    buffers of its own (:func:`own_buffers`: the sharded program donates
+    the carries on accelerators) and ``params_specs`` the hashable
+    PartitionSpec tree for the sharded program."""
     n = fleet_size(mesh)
     F = int(np.shape(keys)[0])
     if F % n != 0:
@@ -185,4 +215,6 @@ def shard_fleet(mesh: Mesh, keys, states, env_states, env_params, ref):
     env_params = jax.tree.map(
         lambda x, s: put_global(x, NamedSharding(mesh, s)),
         env_params, params_specs)
+    keys, states, env_states = own_buffers(env_params,
+                                           (keys, states, env_states))
     return keys, states, env_states, env_params, params_specs

@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig
-from repro.treepath import keystr_path
 
 
 @dataclasses.dataclass(frozen=True)
@@ -178,7 +177,7 @@ class ShardingPolicy:
 
     def params_tree(self, abstract_params) -> Any:
         def spec_for(path, leaf):
-            pstr = keystr_path(path, separator="/")
+            pstr = jax.tree_util.keystr(path, simple=True, separator="/")
             return self.param_spec(pstr, leaf.shape)
         return jax.tree_util.tree_map_with_path(spec_for, abstract_params)
 
@@ -228,7 +227,7 @@ class ShardingPolicy:
 
     def cache_sharding(self, abstract_cache) -> Any:
         def spec(path, leaf):
-            pstr = keystr_path(path, separator="/")
+            pstr = jax.tree_util.keystr(path, simple=True, separator="/")
             return NamedSharding(self.mesh, self.cache_spec(pstr, leaf.shape))
         return jax.tree_util.tree_map_with_path(spec, abstract_cache)
 

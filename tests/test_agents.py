@@ -164,7 +164,8 @@ def test_model_based_no_retrace_across_calls():
     assert X2.shape == X1.shape == X3.shape
 
 
-def test_ddpg_select_pallas_knn_matches_default(small_env):
+def test_ddpg_select_pallas_knn_matches_default(small_env,
+                                                interpreted_knn_kernel):
     """The Pallas-backed K-NN projection is a drop-in for the lax.top_k
     beam inside the DDPG select path (interpret mode on CPU)."""
     env = small_env

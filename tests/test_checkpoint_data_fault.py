@@ -77,7 +77,8 @@ def test_elastic_restore_to_new_topology(tmp_path):
     """Restore places leaves with explicit shardings (single device here,
     but exercises the code path used after re-meshing)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((1,), ("data",))
     ck = Checkpointer(tmp_path)
     st = _state()
     ck.save(1, st)

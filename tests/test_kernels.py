@@ -116,7 +116,7 @@ def test_wkv6_chunk_invariance():
 @given(st.integers(0, 10_000), st.integers(2, 60), st.integers(2, 16))
 def test_knn_topk_vs_ref(seed, n, m):
     proto = jax.random.uniform(jax.random.PRNGKey(seed), (n, m))
-    b, s, r = row_top2_regret(proto, row_blk=16)
+    b, s, r = row_top2_regret(proto, row_blk=128, interpret=True)
     br, sr, rr = row_top2_regret_ref(proto)
     assert bool(jnp.all(b == br))
     assert bool(jnp.all(s == sr))
@@ -126,5 +126,31 @@ def test_knn_topk_vs_ref(seed, n, m):
 
 def test_knn_topk_regret_nonnegative():
     proto = jax.random.uniform(jax.random.PRNGKey(1), (50, 10))
-    _, _, r = row_top2_regret(proto)
+    _, _, r = row_top2_regret(proto, interpret=True)
     assert bool(jnp.all(r >= 0))
+
+
+@pytest.mark.parametrize("lead,n,m,row_blk", [
+    ((), 300, 10, 128),        # several programs, ragged last block
+    ((), 512, 10, 512),        # one wide program
+    ((3,), 100, 10, 512),      # vmapped over a fleet axis
+    ((2, 3), 40, 6, 128),      # nested vmap, as the DDPG target values
+])
+def test_knn_topk_blocks_and_vmap_vs_ref(lead, n, m, row_blk):
+    """Multi-block grids and vmap batching (one grid axis per batch
+    dimension) reduce exactly like lax.top_k: ties to the lowest index,
+    NaN above every number."""
+    proto = jax.random.uniform(jax.random.PRNGKey(n), lead + (n, m))
+    proto = proto.at[..., 0, 1].set(proto[..., 0, 0])       # a tie
+    proto = proto.at[..., 1, 3].set(jnp.nan)                # one NaN
+    proto = proto.at[..., 2, 0].set(jnp.nan).at[..., 2, m - 1].set(jnp.nan)
+    f = lambda p: row_top2_regret(p, row_blk=row_blk, interpret=True)
+    g = row_top2_regret_ref
+    for _ in lead:
+        f, g = jax.vmap(f), jax.vmap(g)
+    b, s, r = f(proto)
+    br, sr, rr = g(proto)
+    np.testing.assert_array_equal(np.asarray(b), np.asarray(br))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(sr))
+    np.testing.assert_allclose(np.asarray(r), np.asarray(rr),
+                               atol=1e-6, rtol=1e-6)

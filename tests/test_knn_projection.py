@@ -79,13 +79,14 @@ def test_jax_beam_contains_exact_1nn():
 
 def test_pallas_beam_matches_xla_beam_exactly():
     """knn_actions_jax(use_pallas=True) routes the top-2/regret reduction
-    through the kernels/knn_topk Pallas kernel (interpret mode on CPU) and
-    must match the lax.top_k beam bit for bit."""
+    through the kernels/knn_topk Pallas kernel (interpret mode, asked for
+    explicitly on CPU) and must match the lax.top_k beam bit for bit."""
     for seed, (n, m, k) in [(0, (40, 10, 8)), (1, (25, 6, 6)),
                             (2, (7, 3, 4)), (3, (100, 10, 16))]:
         proto = jax.random.uniform(jax.random.PRNGKey(seed), (n, m))
         beam = np.asarray(knn_actions_jax(proto, k))
-        pallas = np.asarray(knn_actions_jax(proto, k, use_pallas=True))
+        pallas = np.asarray(knn_actions_jax(proto, k, use_pallas=True,
+                                            interpret=True))
         np.testing.assert_array_equal(pallas, beam)
 
 

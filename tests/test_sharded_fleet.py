@@ -1,7 +1,7 @@
 """Device-sharded scenario fleets (run_online_fleet(..., mesh=...)).
 
-The contract under test: (a) on the host mesh (jax.make_mesh over the one
-CPU device) the sharded path is bit-comparable to the plain vmap runner,
+The contract under test: (a) on the host mesh (launch.mesh.make_host_mesh,
+the one CPU device) the sharded path is bit-comparable to the plain vmap runner,
 (b) params partition specs mirror params_in_axes (stacked leaves shard,
 broadcast-invariant leaves replicate) and stay hashable, (c) indivisible
 fleets fail loudly, and (d) on a REAL 2-device mesh (subprocess with
@@ -19,13 +19,14 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import ddpg, make_agent
-from repro.core.agent import run_online_fleet
+from repro.core.agent import reset_fleet_states, run_online_fleet
 from repro.core.ddpg import DDPGConfig
 from repro.dsdps import SchedulingEnv, apps, scenarios
 from repro.dsdps.apps import default_workload
 from repro.launch.mesh import make_host_mesh
 from repro.sharding.fleet import (fleet_axes, fleet_shardings, fleet_size,
-                                  fleet_spec, params_partition_specs)
+                                  fleet_spec, params_partition_specs,
+                                  shard_fleet)
 
 
 @pytest.fixture(scope="module")
@@ -75,6 +76,29 @@ def test_fleet_shardings_shapes(small_env):
     assert sh["stacked"].spec == P(("data",))
     assert sh["vector"].spec == P(("data",))
     assert sh["scalar"].spec == P()          # scalars replicate
+
+
+def test_shard_fleet_gives_every_carry_leaf_its_own_buffer(small_env,
+                                                           ddpg_agent):
+    """The sharded program donates the carries on accelerators, and a
+    donating call refuses a buffer passed twice: init_fleet returns the
+    online nets as their targets, and reset passes params arrays through."""
+    env, F = small_env, 2
+    states = ddpg_agent.init_fleet(jax.random.PRNGKey(0), F)
+    keys = jax.random.split(jax.random.PRNGKey(1), F)
+    env_states = reset_fleet_states(keys, env)
+    ref = env.default_params()
+
+    def buffers(tree):
+        return [(s.device.id, s.data.unsafe_buffer_pointer())
+                for x in jax.tree.leaves(tree) for s in x.addressable_shards]
+
+    assert len(set(buffers(states))) < len(buffers(states))   # aliased in
+    keys, states, env_states, params, _ = shard_fleet(
+        make_host_mesh(), keys, states, env_states, ref, ref)
+    carried = buffers((keys, states, env_states))
+    assert len(set(carried)) == len(carried)
+    assert not set(carried) & set(buffers(params))
 
 
 def test_host_mesh_lane_equivalence(small_env, ddpg_agent):

@@ -6,7 +6,6 @@ import pytest
 
 import jax
 from repro.configs import ARCH_IDS, get_config
-from repro.treepath import keystr_path
 from repro.models import lm
 from repro.models.config import ModelConfig
 
@@ -82,7 +81,7 @@ def test_cache_specs_divisible(arch_id):
         enc_len=1024 if cfg.family == "encdec" else 0))
     flat = jax.tree_util.tree_flatten_with_path(cache)[0]
     for kp, leaf in flat:
-        path = keystr_path(kp, separator="/")
+        path = jax.tree_util.keystr(kp, simple=True, separator="/")
         spec = policy.cache_spec(path, leaf.shape)
         for dim, ax in zip(leaf.shape, tuple(spec)):
             if ax is None:

@@ -102,13 +102,15 @@ def test_fields_split_is_a_distribution_shared_by_all_senders(
             np.testing.assert_allclose(fracs.sum(axis=1), 1.0, rtol=1e-12)
             # the key-hash split is a property of the EDGE: every
             # upstream executor sees the identical (skewed) distribution
-            np.testing.assert_allclose(fracs, fracs[:1], rtol=1e-12)
+            for row in fracs[1:]:
+                np.testing.assert_allclose(row, fracs[0], rtol=1e-12)
         if e.grouping == SHUFFLE:
             np.testing.assert_allclose(fracs, 1.0 / len(dst_ids), rtol=1e-12)
         if e.grouping == GLOBAL:
             expect = np.zeros(len(dst_ids))
             expect[0] = 1.0
-            np.testing.assert_allclose(fracs, expect[None, :], atol=1e-15)
+            np.testing.assert_allclose(
+                fracs, np.broadcast_to(expect, fracs.shape), atol=1e-15)
         if e.grouping == ALL:
             np.testing.assert_allclose(fracs, 1.0, rtol=1e-12)
 

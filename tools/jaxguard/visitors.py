@@ -38,7 +38,7 @@ _TRACE_ENTRY = _JIT | _VMAP | {
     "jax.lax.scan", "jax.lax.map", "jax.lax.while_loop", "jax.lax.cond",
     "jax.lax.fori_loop", "jax.lax.associative_scan", "jax.grad",
     "jax.value_and_grad", "jax.checkpoint", "jax.remat", "jax.linearize",
-    "jax.experimental.shard_map.shard_map",
+    "jax.shard_map", "jax.experimental.shard_map.shard_map",
 }
 # jnp constructors whose all-literal calls are per-iteration h2d transfers
 _JNP_CONSTRUCTORS = {
