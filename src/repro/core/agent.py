@@ -63,7 +63,8 @@ import numpy as np
 
 from repro.core import ddpg, dqn
 from repro.core.api import Agent, make_epoch_step
-from repro.diagnostics import maybe_check_finite
+from repro.diagnostics import maybe_check_finite, note_compile, span
+from repro.diagnostics.guards import _cache_size
 from repro.core.ddpg import DDPGConfig, DDPGState
 from repro.core.dqn import DQNConfig, DQNState
 from repro.sharding.fleet import fleet_host, fleet_spec, shard_fleet
@@ -249,7 +250,10 @@ def run_fleet_chunk(keys, states, env_states, env_params, *, env,
     — slice anything you still need out of them (e.g. a stopped lane's
     final state) before calling again.  Returns the evolved carries plus
     the ``[fleet, T]`` traces: ``(states, env_states, keys, rewards,
-    latencies, moved)``."""
+    latencies, moved)``.
+
+    The call runs inside the ``fleet.dispatch`` span, and a call that
+    compiled is recorded for ``diagnostics.scope_tables``."""
     common = dict(env=env, agent=agent, T=int(T),
                   updates_per_epoch=int(updates_per_epoch),
                   explore=bool(explore), params_axes=params_axes)
@@ -260,7 +264,12 @@ def run_fleet_chunk(keys, states, env_states, env_params, *, env,
         common.update(mesh=mesh, params_specs=params_specs)
     else:
         program = _fleet_program
-    return program(keys, states, env_states, env_params, **common)
+    args = (keys, states, env_states, env_params)
+    with span("fleet.dispatch"):
+        cached = _cache_size(program)
+        out = program(*args, **common)
+        note_compile(program, cached, args, common)
+    return out
 
 
 def chunk_schedule(T: int, every: int | None) -> list[int]:
@@ -282,11 +291,11 @@ def prepare_fleet(keys, env, states, env_states, env_params, mesh):
     points, which is why it is one function.
 
     Returns ``(keys, states, env_states, env_params, ref, params_axes,
-    params_specs)``."""
+    params_specs)``.  Runs inside the ``fleet.prepare`` span."""
     # setup preamble exemption: placing hosts arrays on devices is this
     # function's JOB, so the diagnostics transfer guard (which polices the
     # steady-state chunk loop) is lifted for its dynamic extent
-    with jax.transfer_guard("allow"):
+    with span("fleet.prepare"), jax.transfer_guard("allow"):
         keys = jnp.asarray(keys)
         ref = env.default_params()
         if env_params is None:
@@ -438,46 +447,53 @@ def run_online_fleet(
                  directly for the per-lane stop epochs and the
                  executed-lane-epoch accounting.
 
-    Returns (stacked agent states, History with [fleet, T] traces)."""
-    agent = _require_agent(agent)
-    T = int(T)
-    if T < 1:
-        raise ValueError(f"T must be >= 1, got {T}")
-    if lifecycle is not None:
-        from repro.fleet.lifecycle import run_online_fleet_elastic
-        result = run_online_fleet_elastic(
-            keys, env, agent, states, T, rule=lifecycle,
-            updates_per_epoch=updates_per_epoch, explore=explore,
-            env_states=env_states, env_params=env_params, mesh=mesh,
-            checkpoint=checkpoint, start_epoch=start_epoch)
-        return result.states, result.history
-    keys, states, env_states, env_params, _, params_axes, params_specs = \
-        prepare_fleet(keys, env, states, env_states, env_params, mesh)
+    Returns (stacked agent states, History with [fleet, T] traces).  The
+    call runs inside one ``fleet.job`` span, the parent of its
+    ``fleet.prepare``, ``fleet.dispatch`` and ``fleet.pull`` (the pulls of
+    its traces and final assignment) spans."""
+    with span("fleet.job"):
+        agent = _require_agent(agent)
+        T = int(T)
+        if T < 1:
+            raise ValueError(f"T must be >= 1, got {T}")
+        if lifecycle is not None:
+            from repro.fleet.lifecycle import run_online_fleet_elastic
+            result = run_online_fleet_elastic(
+                keys, env, agent, states, T, rule=lifecycle,
+                updates_per_epoch=updates_per_epoch, explore=explore,
+                env_states=env_states, env_params=env_params, mesh=mesh,
+                checkpoint=checkpoint, start_epoch=start_epoch)
+            return result.states, result.history
+        keys, states, env_states, env_params, _, params_axes, params_specs = \
+            prepare_fleet(keys, env, states, env_states, env_params, mesh)
 
-    every = getattr(checkpoint, "every", None) if checkpoint is not None \
-        else None
-    epoch = int(start_epoch)
-    r_parts, l_parts, m_parts = [], [], []
-    for n in chunk_schedule(T, every):
-        states, env_states, keys, rewards, lats, moved = run_fleet_chunk(
-            keys, states, env_states, env_params, env=env, agent=agent,
-            T=n, updates_per_epoch=updates_per_epoch, explore=explore,
-            params_axes=params_axes, mesh=mesh, params_specs=params_specs)
-        # fleet_host == np.asarray off a spanning mesh; on one it
-        # allgathers the trace shards so every process sees the full
-        # [fleet, T] history (multi-host runs return identical Histories
-        # on every process)
-        r_parts.append(fleet_host(rewards))
-        l_parts.append(fleet_host(lats))
-        m_parts.append(fleet_host(moved))
-        epoch += n
-        maybe_check_finite((states, rewards), f"run_online_fleet epoch {epoch}")
-        if checkpoint is not None:
-            checkpoint.save(epoch, states, env_states, keys)
-    return states, History(rewards=np.concatenate(r_parts, axis=-1),
-                           latencies=np.concatenate(l_parts, axis=-1),
-                           moved=np.concatenate(m_parts, axis=-1),
-                           final_assignment=fleet_host(env_states.X))
+        every = getattr(checkpoint, "every", None) if checkpoint is not None \
+            else None
+        epoch = int(start_epoch)
+        r_parts, l_parts, m_parts = [], [], []
+        for n in chunk_schedule(T, every):
+            states, env_states, keys, rewards, lats, moved = run_fleet_chunk(
+                keys, states, env_states, env_params, env=env, agent=agent,
+                T=n, updates_per_epoch=updates_per_epoch, explore=explore,
+                params_axes=params_axes, mesh=mesh, params_specs=params_specs)
+            # fleet_host == np.asarray off a spanning mesh; on one it
+            # allgathers the trace shards so every process sees the full
+            # [fleet, T] history (multi-host runs return identical Histories
+            # on every process)
+            with span("fleet.pull"):
+                r_parts.append(fleet_host(rewards))
+                l_parts.append(fleet_host(lats))
+                m_parts.append(fleet_host(moved))
+            epoch += n
+            maybe_check_finite((states, rewards), f"run_online_fleet epoch {epoch}")
+            if checkpoint is not None:
+                checkpoint.save(epoch, states, env_states, keys)
+        with span("fleet.pull"):
+            final_assignment = fleet_host(env_states.X)
+        return states, History(rewards=np.concatenate(r_parts, axis=-1),
+                               latencies=np.concatenate(l_parts, axis=-1),
+                               moved=np.concatenate(m_parts, axis=-1),
+                               final_assignment=final_assignment)
 
 
 # --------------------------------------------------------------------------

@@ -51,6 +51,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.diagnostics.spans import AGENT_SELECT, AGENT_UPDATE, ENV_STEP
+
 
 class Agent(NamedTuple):
     """Optax-style bundle of pure control-policy functions.
@@ -138,26 +140,35 @@ def make_epoch_step(env, agent: Agent, env_params=None,
     legacy per-agent Python loops (core.agent.run_online_*_python) exactly,
     so scan runners reproduce their traces.  ``env_params`` may be a traced
     pytree (the fleet runner passes one lane of a stacked scenario fleet);
-    None freezes the env's defaults into the program as constants."""
+    None freezes the env's defaults into the program as constants.
+
+    The env step, the select and the update (observe, the update scan,
+    tick) each run under their ``jax.named_scope`` of
+    ``diagnostics.spans.LAYERS``; a profile maps the compiled ops back to
+    them (``diagnostics.scope_tables``).  The key split stays unscoped."""
     params = env.default_params() if env_params is None else env_params
 
     def epoch_step(carry, _):
         state, env_state, key = carry
         key, k_act, k_step, k_upd = jax.random.split(key, 4)
-        s_vec = env.state_vector(env_state, params)
-        action, aux = agent.select_fn(k_act, agent.cfg, state, s_vec,
-                                      env_state, params, explore)
-        out = env.step(k_step, env_state, action, params)
-        s_next = env.state_vector(out.state, params)
-        state = agent.observe_fn(agent.cfg, state, s_vec, aux, out.reward,
-                                 s_next)
+        with jax.named_scope(ENV_STEP):
+            s_vec = env.state_vector(env_state, params)
+        with jax.named_scope(AGENT_SELECT):
+            action, aux = agent.select_fn(k_act, agent.cfg, state, s_vec,
+                                          env_state, params, explore)
+        with jax.named_scope(ENV_STEP):
+            out = env.step(k_step, env_state, action, params)
+            s_next = env.state_vector(out.state, params)
+        with jax.named_scope(AGENT_UPDATE):
+            state = agent.observe_fn(agent.cfg, state, s_vec, aux,
+                                     out.reward, s_next)
 
-        def upd(st, k):
-            return agent.update_fn(k, agent.cfg, st), None
+            def upd(st, k):
+                return agent.update_fn(k, agent.cfg, st), None
 
-        state, _ = jax.lax.scan(
-            upd, state, jax.random.split(k_upd, updates_per_epoch))
-        state = agent.tick_fn(agent.cfg, state)
+            state, _ = jax.lax.scan(
+                upd, state, jax.random.split(k_upd, updates_per_epoch))
+            state = agent.tick_fn(agent.cfg, state)
         return (state, out.state, key), (out.reward, out.latency_ms, out.moved)
 
     return epoch_step

@@ -54,7 +54,7 @@ import numpy as np
 from repro.core.agent import (History, chunk_schedule, prepare_fleet,
                               reset_fleet_states, run_fleet_chunk)
 from repro.core.api import Agent
-from repro.diagnostics import maybe_check_finite
+from repro.diagnostics import maybe_check_finite, span
 from repro.dsdps.simulator import lane_params, params_in_axes, stack_env_params
 from repro.sharding.fleet import (compaction_size, fleet_host,
                                   fleet_host_tree, is_spanning, shard_fleet)
@@ -248,7 +248,8 @@ def run_online_fleet_elastic(
         executed += len(orig) * n
         maybe_check_finite((states, rewards),
                            f"run_online_fleet_elastic epoch {start_epoch + t + n}")
-        r, l, m = fleet_host(rewards), fleet_host(lats), fleet_host(moved)
+        with span("fleet.pull"):
+            r, l, m = fleet_host(rewards), fleet_host(lats), fleet_host(moved)
         rows = orig[live]
         rewards_buf[rows, t:t + n] = r[live]
         lats_buf[rows, t:t + n] = l[live]

@@ -5,10 +5,15 @@ Entry points (``drl_control``, ``serve_control``, the benchmark CLIs and
 script, before their ``main``; importing this module, or calling a
 ``main`` in-process, changes nothing.  The cache lives where
 ``JAX_COMPILATION_CACHE_DIR`` says when that is set (jax reads the
-variable itself, so nothing is configured), and otherwise in
+variable itself, so no path is configured), and otherwise in
 ``<checkout>/.jax_cache``.  A later run finds only what an earlier run
 wrote to the same path, so it must not move between runs: never a
 temporary, per-process or dated one.
+
+The cache key holds the programs' metadata too.  JAX leaves it out by
+default, and a program that differs from a cached one only in its
+``jax.named_scope``s would then load that executable, whose ``op_name``s
+are the other lowering's (``diagnostics.scope_tables`` reads them).
 """
 from __future__ import annotations
 
@@ -30,6 +35,7 @@ def compile_cache_dir() -> str:
 
 def enable_compile_cache() -> str:
     """Turn the persistent cache on (see module doc); returns its path."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = compile_cache_dir()
     if not os.environ.get(ENV_VAR):
         jax.config.update("jax_compilation_cache_dir", path)

@@ -88,9 +88,11 @@ def test_enable_compile_cache_sets_only_the_fixed_dir(monkeypatch, tmp_path):
     monkeypatch.setattr(jax.config, "update",
                         lambda name, value: calls.append((name, value)))
     monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    metadata = ("jax_compilation_cache_include_metadata_in_key", True)
     assert compile_cache.enable_compile_cache() == str(tmp_path)
-    assert calls == []                 # jax reads the variable itself
+    assert calls == [metadata]         # jax reads the variable itself
     monkeypatch.delenv(compile_cache.ENV_VAR)
+    calls.clear()
     assert compile_cache.enable_compile_cache() == str(ROOT / ".jax_cache")
-    assert calls == [("jax_compilation_cache_dir",
-                      str(ROOT / ".jax_cache"))]
+    assert calls == [metadata, ("jax_compilation_cache_dir",
+                                str(ROOT / ".jax_cache"))]
