@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from repro.core import api
 from repro.core import networks as nets
 from repro.core.exploration import EpsilonSchedule, epsilon_greedy
-from repro.core.replay import Replay, replay_add, replay_init, replay_sample
+from repro.core.replay import (Replay, pick, replay_add, replay_init,
+                               replay_sample)
 from repro.train.optimizer import adam, apply_updates
 
 
@@ -83,7 +84,7 @@ def update_step(key, state: DQNState, cfg: DQNConfig):
 
     def loss(qp):
         q = jax.vmap(lambda sv: nets.apply_qnet(qp, sv))(s)
-        q_sa = jnp.take_along_axis(q, a[:, None], axis=-1)[:, 0]
+        q_sa = pick(q, a)
         return jnp.mean(jnp.square(y - q_sa))
 
     l, grads = jax.value_and_grad(loss)(state.qnet)

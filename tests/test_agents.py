@@ -8,7 +8,7 @@ import pytest
 from repro.core import (DDPGConfig, DQNConfig, ModelBasedScheduler,
                         ddpg_init, dqn_init, round_robin)
 from repro.core import ddpg, dqn
-from repro.core.replay import replay_add, replay_init, replay_sample
+from repro.core.replay import pick, replay_add, replay_init, replay_sample
 from repro.dsdps import SchedulingEnv, apps
 from repro.dsdps.apps import default_workload
 
@@ -31,6 +31,30 @@ def test_replay_ring_buffer_semantics():
     assert stored == {2.0, 3.0, 4.0, 5.0}
     s, a, r, sn = replay_sample(jax.random.PRNGKey(0), buf, 16)
     assert s.shape == (16, 3) and r.shape == (16,)
+
+
+@pytest.mark.parametrize("batch,moves", [
+    (32, 1000),     # the DQN's H = 32 over 1000 moves
+    (4, 1),         # one move: the sum has a single term
+])
+def test_q_of_taken_move_pick_matches_take_along_axis(batch, moves):
+    """Q(s, a) as the DQN update reads it: value and VJP equal
+    ``take_along_axis``'s bit for bit."""
+    kq, ka, kg = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(kq, (batch, moves))
+    a = jax.random.randint(ka, (batch,), 0, moves)
+    g = jax.random.normal(kg, (batch,))
+
+    def gather(q):
+        return jnp.take_along_axis(q, a[:, None], axis=-1)[:, 0]
+
+    got, got_vjp = jax.vjp(lambda q: pick(q, a), q)
+    want, want_vjp = jax.vjp(gather, q)
+
+    def bits(x):
+        return np.asarray(x).view(np.uint32)
+    np.testing.assert_array_equal(bits(got), bits(want))
+    np.testing.assert_array_equal(bits(got_vjp(g)[0]), bits(want_vjp(g)[0]))
 
 
 def test_ddpg_select_action_feasible(small_env):

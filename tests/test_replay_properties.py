@@ -86,3 +86,32 @@ def test_overwritten_transitions_never_resurface(capacity, extra):
     _, _, r, _ = replay_sample(jax.random.PRNGKey(0), buf, 256)
     overwritten = set(float(t) for t in range(1, n - capacity + 1))
     assert not (set(np.asarray(r).tolist()) & overwritten)
+
+
+@settings(max_examples=40, deadline=None)
+@given(capacity=st.integers(min_value=1, max_value=12),
+       n=st.integers(min_value=0, max_value=30),
+       batch=st.integers(min_value=1, max_value=64),
+       action_dim=st.sampled_from([1, 3]),
+       seed=st.integers(min_value=0, max_value=2**31 - 1))
+def test_sample_equals_plain_gather_bit_for_bit(capacity, n, batch,
+                                                action_dim, seed):
+    """``replay_sample`` returns exactly ``col[idx]`` of every column, with
+    ``idx`` drawn from the same key: the select-reduce that reads a
+    one-value column (rewards; a one-wide action) changes no bit, and the
+    wide action rows keep their gather."""
+    rng = np.random.default_rng(seed)
+    buf = replay_init(capacity, 3, action_dim)
+    for _ in range(n):
+        buf = replay_add(buf, *(jnp.asarray(rng.standard_normal(shape),
+                                            jnp.float32)
+                                for shape in ((3,), (action_dim,), (), (3,))))
+    key = jax.random.PRNGKey(seed)
+    idx = jax.random.randint(key, (batch,), 0, max(min(n, capacity), 1))
+    want = (buf.states[idx], buf.actions[idx], buf.rewards[idx],
+            buf.next_states[idx])
+    got = replay_sample(key, buf, batch)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g).view(np.uint32),
+                                      np.asarray(w).view(np.uint32))

@@ -6,6 +6,7 @@ or XLA:TPU would refuse (tiling, VMEM, device memory) fails these tests
 without a chip.  Nothing runs.  The topology is described inside a fixture,
 never at import: only one process may load the TPU library at a time."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,26 +56,49 @@ def test_row_top2_regret_compiles_for_v5e(one_chip, shape, vmaps):
     assert "tpu_custom_call" in text
 
 
-def test_cq_large_ddpg_fleet_epoch_compiles_for_one_v5e(one_chip):
+def _compile_cq_large_fleet_epoch(agent_name, sharding):
     """The fused epoch program (select → env.step → store → update) of a
-    128-lane cq_large ddpg fleet fits one chip."""
+    128-lane cq_large fleet, compiled for ``sharding``'s device."""
     from repro.core import make_agent
     from repro.core.agent import _fleet_program, reset_fleet_states
     from repro.launch.drl_control import build_env
     env = build_env("cq_large")
-    agent = make_agent("ddpg", env)
+    agent = make_agent(agent_name, env)
     fleet = 128
     key = jax.random.PRNGKey(0)
     keys = jax.random.split(key, fleet)
     states = jax.eval_shape(lambda: agent.init_fleet(key, fleet))
     env_states = jax.eval_shape(lambda: reset_fleet_states(keys, env))
-    args = [_shaped(t, one_chip)
+    args = [_shaped(t, sharding)
             for t in (keys, states, env_states, env.default_params())]
-    compiled = _fleet_program.lower(
+    return _fleet_program.lower(
         *args, env=env, agent=agent, T=1, updates_per_epoch=1,
         explore=True, params_axes=None).compile()
+
+
+def test_cq_large_ddpg_fleet_epoch_compiles_for_one_v5e(one_chip):
+    """The fused epoch program of a 128-lane cq_large ddpg fleet fits one
+    chip."""
+    compiled = _compile_cq_large_fleet_epoch("ddpg", one_chip)
     mem = compiled.memory_analysis()
-    print(f"cq_large ddpg fleet F={fleet} epoch on one v5e: {mem}")
+    print(f"cq_large ddpg fleet F=128 epoch on one v5e: {mem}")
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert 0 < total < 16e9
+
+
+def test_cq_large_dqn_update_gathers_only_state_rows_on_v5e(one_chip):
+    """The DQN update of a 128-lane cq_large fleet (minibatch 32, 1010-wide
+    states, 1000 moves) gathers only the two state-row blocks of its
+    minibatch: the rewards, the moves and Q(s, a), one value per sample,
+    are select-reduces, since the chip prices a gather per index."""
+    text = _compile_cq_large_fleet_epoch("dqn", one_chip).as_text()
+    shapes = []
+    for line in text.splitlines():
+        op = re.search(r'op_name="([^"]*)"', line)
+        out = re.search(r"= \w+\[([\d,]*)\]\S* gather\(", line)
+        if op and out and "agent_update" in op.group(1):
+            shapes.append(tuple(int(d) for d in out.group(1).split(",")))
+    assert len(shapes) == 2, shapes
+    for shape in shapes:
+        assert shape in ((4096, 1010), (128, 32, 1010)), shapes
