@@ -19,6 +19,7 @@ from repro.core import networks as nets
 from repro.core.exploration import EpsilonSchedule, perturb_proto
 from repro.core.knn_projection import knn_actions_exact, knn_actions_jax
 from repro.core.replay import Replay, replay_add, replay_init, replay_sample
+from repro.diagnostics.spans import CRITIC_TARGET
 from repro.train.optimizer import adam, apply_updates
 
 
@@ -130,7 +131,8 @@ def select_action_jit(key, state: DDPGState, cfg: DDPGConfig, s_vec, explore: bo
 # One learning update (lines 13-18)
 # --------------------------------------------------------------------------
 def _target_values(state: DDPGState, cfg: DDPGConfig, r, s_next):
-    """y_i = r_i + γ max_{a∈A_K(f'(s'))} Q'(s', a)   (line 15)."""
+    """y_i = r_i + γ max_{a∈A_K(f'(s'))} Q'(s', a)   (line 15), under the
+    ``critic_target`` scope (diagnostics.SUBSCOPES)."""
     def per_sample(sv):
         proto = nets.apply_actor(state.target_actor, sv).reshape(
             cfg.n_executors, cfg.n_machines)
@@ -140,8 +142,9 @@ def _target_values(state: DDPGState, cfg: DDPGConfig, r, s_next):
             lambda a: nets.apply_critic(state.target_critic, sv, a.reshape(-1))
         )(cands)
         return q.max()
-    q_next = jax.vmap(per_sample)(s_next)
-    return r + cfg.gamma * q_next
+    with jax.named_scope(CRITIC_TARGET):
+        q_next = jax.vmap(per_sample)(s_next)
+        return r + cfg.gamma * q_next
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -169,15 +172,14 @@ def update_step(key: jax.Array, state: DDPGState, cfg: DDPGConfig) -> tuple:
     a_upd, opt_a_state = opt_a.update(a_grads, state.opt_actor, state.actor)
     actor = apply_updates(state.actor, a_upd)
 
-    new_state = DDPGState(
+    # _replace: the replay, the epoch and the reward statistics carry over
+    new_state = state._replace(
         actor=actor,
         critic=critic,
         target_actor=nets.soft_update(state.target_actor, actor, cfg.tau),
         target_critic=nets.soft_update(state.target_critic, critic, cfg.tau),
         opt_actor=opt_a_state,
         opt_critic=opt_c_state,
-        replay=state.replay,
-        epoch=state.epoch,
     )
     return new_state, {"critic_loss": c_loss, "actor_loss": a_loss}
 

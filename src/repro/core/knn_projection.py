@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.diagnostics.spans import KNN_PROJECTION
 
 # --------------------------------------------------------------------------
 # Host path: exact best-first k-best enumeration (replaces Gurobi loop).
@@ -107,7 +108,14 @@ def knn_actions_jax(
     proto: jnp.ndarray, k: int, pair_pool: int = 8, triple_pool: int = 4,
     use_pallas: bool = False, interpret: bool = False,
 ) -> jnp.ndarray:
-    """[k, N, M] one-hot candidate actions, ordered by distance to proto."""
+    """[k, N, M] one-hot candidate actions, ordered by distance to proto,
+    under the ``knn_projection`` scope (diagnostics.SUBSCOPES)."""
+    with jax.named_scope(KNN_PROJECTION):
+        return _knn_beam(proto, k, pair_pool, triple_pool, use_pallas,
+                         interpret)
+
+
+def _knn_beam(proto, k, pair_pool, triple_pool, use_pallas, interpret):
     n, m = proto.shape
     # best / 2nd-best machine per row + single-flip regret to the 2nd-best
     best_col, second_col, flip_regret = _row_top2(proto, use_pallas,

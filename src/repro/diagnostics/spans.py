@@ -11,6 +11,13 @@ those scopes.
   ``api.make_epoch_step`` puts around the env step, the agent's select
   and its update.  A scope is compile-time metadata: it lands in each
   HLO instruction's ``op_name`` and changes no operation.
+* :data:`SUBSCOPES` are finer ``jax.named_scope`` names inside a layer:
+  ``knn_projection`` around ``core.knn_projection.knn_actions_jax`` (the
+  select's beam and the target's alike) and ``critic_target`` around
+  ``core.ddpg._target_values`` (the target actor, its K-NN beam and the
+  target critic over the candidates).  They nest, so an instruction's
+  sub-scope is the path of those on its ``op_name``, outermost first
+  (``critic_target/knn_projection``).
 * :func:`note_compile` is called by the fleet runner after each program
   call.  When the program's trace cache grew (a compile) it records the
   program, its static arguments and the shapes of its arguments; on a
@@ -22,6 +29,7 @@ those scopes.
   device trace names its ops by those instruction names (``%fusion.12``),
   so the table gives each op's layer.  A fusion that spans two scopes
   carries its root instruction's ``op_name``, and goes to that scope.
+  :func:`subscope_tables` does the same with :func:`subscope_of`.
 
 The executable a run used may come from the persistent compile cache.
 Its ``op_name`` paths are its own only when the cache key holds the
@@ -39,9 +47,12 @@ from repro.diagnostics.guards import _cache_size
 
 ENV_STEP, AGENT_SELECT, AGENT_UPDATE = LAYERS = (
     "env_step", "agent_select", "agent_update")
+KNN_PROJECTION, CRITIC_TARGET = SUBSCOPES = ("knn_projection",
+                                             "critic_target")
 
-# (program, statics, arg treedef, arg specs) -> that compile's table, or
-# None until scope_tables() builds it
+# (program, statics, arg treedef, arg specs) -> (module name,
+# {instruction name: op_name or None}) of that compile, or None until a
+# table is first asked for
 _COMPILED: dict = {}
 
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
@@ -87,39 +98,61 @@ def layer_of(op_name: str) -> str | None:
     return None
 
 
-def parse_hlo(text: str) -> tuple[str, dict]:
-    """(module name, {instruction name: layer or None}) of an HLO text."""
-    module, table = "", {}
+def subscope_of(op_name: str) -> str | None:
+    """The :data:`SUBSCOPES` on an ``op_name`` path, outermost first and
+    joined by ``/`` (``critic_target/knn_projection``), or None."""
+    found = [p for p in re.split(r"[/()]", op_name) if p in SUBSCOPES]
+    return "/".join(dict.fromkeys(found)) or None
+
+
+def _op_names(text: str) -> tuple[str, dict]:
+    """(module name, {instruction name: op_name or None}) of an HLO text."""
+    module, ops = "", {}
     for line in text.splitlines():
         if not module and (m := _MODULE.match(line)):
             module = m.group(1)
             continue
         if m := _INSTRUCTION.match(line):
             op = _OP_NAME.search(line)
-            table[m.group(1)] = layer_of(op.group(1)) if op else None
-    return module, table
+            ops[m.group(1)] = op.group(1) if op else None
+    return module, ops
 
 
-def scope_tables() -> dict[str, dict]:
+def parse_hlo(text: str) -> tuple[str, dict]:
+    """(module name, {instruction name: layer or None}) of an HLO text."""
+    module, ops = _op_names(text)
+    return module, {name: layer_of(op) if op else None
+                    for name, op in ops.items()}
+
+
+def scope_tables(scope_of=layer_of) -> dict[str, dict]:
     """``{program trace name: {instruction name: layer or None}}`` over
-    every program :func:`note_compile` recorded.  Compiles of one program
-    under the same trace name merge; an instruction name whose layer
+    every program :func:`note_compile` recorded (with ``scope_of``
+    another classification of the ``op_name``s).  Compiles of one program
+    under the same trace name merge; an instruction name whose scope
     differs between them is left out, so a reader cannot misplace it."""
-    for key, table in _COMPILED.items():
-        if table is None:
+    for key, ops in _COMPILED.items():
+        if ops is None:
             program, statics, tree, specs = key
             lowered = program.lower(*jax.tree.unflatten(tree, specs),
                                     **dict(statics))
-            _COMPILED[key] = parse_hlo(lowered.compile().as_text())
+            _COMPILED[key] = _op_names(lowered.compile().as_text())
     merged: dict[str, dict] = {}
     clash: dict[str, set] = {}
-    for module, table in _COMPILED.values():
+    for module, ops in _COMPILED.values():
         out = merged.setdefault(module, {})
         bad = clash.setdefault(module, set())
-        for name, layer in table.items():
+        for name, op in ops.items():
+            layer = scope_of(op) if op else None
             if out.setdefault(name, layer) != layer:
                 bad.add(name)
     for module, bad in clash.items():
         for name in bad:
             del merged[module][name]
     return merged
+
+
+def subscope_tables() -> dict[str, dict]:
+    """``{program trace name: {instruction name: sub-scope path or
+    None}}``, built as :func:`scope_tables` builds the layer table."""
+    return scope_tables(subscope_of)

@@ -113,6 +113,40 @@ def test_ddpg_target_network_soft_update(small_env):
                                rtol=1e-5, atol=1e-6)
 
 
+def test_ddpg_reward_statistics_survive_the_update(small_env):
+    """store → update_step → store: the running reward statistics carry
+    over the update, so the second reward is standardized against the
+    first and is not stored as (r − r) / 1e-4 = 0."""
+    env = small_env
+    cfg = DDPGConfig(n_executors=env.N, n_machines=env.M,
+                     state_dim=env.state_dim, k_nn=2)
+    state = ddpg_init(jax.random.PRNGKey(0), cfg)
+    s = jnp.zeros((cfg.state_dim,))
+    a = jnp.zeros((cfg.action_dim,))
+    state = ddpg.store(state, s, a, jnp.float32(-3.0), s)
+    mean1, var1 = float(state.r_mean), float(state.r_var)
+    state, _ = ddpg.update_step(jax.random.PRNGKey(1), state, cfg)
+    assert int(state.r_count) == 1
+    assert (float(state.r_mean), float(state.r_var)) == (mean1, var1)
+    state = ddpg.store(state, s, a, jnp.float32(-5.0), s)
+    assert int(state.r_count) == 2
+    assert float(state.r_mean) != mean1 and float(state.r_var) != var1
+    assert float(state.replay.rewards[1]) != 0.0
+
+
+def test_ddpg_offline_pretrain_keeps_its_reward_statistics(small_env):
+    """The statistics offline_pretrain sets over its samples survive its
+    update scan: r_count is the number of offline samples."""
+    env = small_env
+    cfg = DDPGConfig(n_executors=env.N, n_machines=env.M,
+                     state_dim=env.state_dim, k_nn=2, buffer=16)
+    state = ddpg_init(jax.random.PRNGKey(0), cfg)
+    state = ddpg.offline_pretrain(jax.random.PRNGKey(1), state, cfg, env,
+                                  n_samples=24, n_updates=3)
+    assert int(state.r_count) == 24
+    assert float(state.r_mean) != 0.0 and float(state.r_var) != 1.0
+
+
 def test_dqn_move_semantics():
     X = jax.nn.one_hot(jnp.array([0, 1, 2]), 4)
     X2 = dqn.apply_move(X, jnp.asarray(1 * 4 + 3), 4)  # executor 1 -> machine 3

@@ -2,9 +2,10 @@
 
 The fused epoch's layer scopes reach the compiled program, and
 ``scope_tables()`` maps its instructions to them from the program that
-ran; the compile registry records a compile once and a cache hit never;
-a profiled job nests its prepare, dispatch and pull spans inside one job
-span on one host thread."""
+ran; ``subscope_tables()`` does the same for the DDPG sub-scopes, which
+no DQN instruction carries; the compile registry records a compile once
+and a cache hit never; a profiled job nests its prepare, dispatch and
+pull spans inside one job span on one host thread."""
 import contextlib
 
 import jax
@@ -14,7 +15,8 @@ import pytest
 
 from repro.core import make_agent
 from repro.core.agent import prepare_fleet, run_online_fleet
-from repro.diagnostics import LAYERS, note_compile, scope_tables, spans
+from repro.diagnostics import (LAYERS, SUBSCOPES, note_compile,
+                               scope_tables, spans, subscope_tables)
 from repro.dsdps import SchedulingEnv, apps, scenarios
 from repro.launch import compile_cache
 
@@ -30,11 +32,10 @@ def registry(monkeypatch):
     return fresh
 
 
-@pytest.fixture(scope="module")
-def job():
+def _job(agent_name):
     topo = apps.continuous_queries("small")
     env = SchedulingEnv(topo, apps.default_workload(topo))
-    agent = make_agent("dqn", env)
+    agent = make_agent(agent_name, env)
     fleet = scenarios.build("mixed", env, F, seed=0)
     states = agent.init_fleet(jax.random.PRNGKey(0), F, env_params=fleet,
                               env=env)
@@ -47,6 +48,16 @@ def job():
     return env, agent, fleet, states, run
 
 
+@pytest.fixture(scope="module")
+def job():
+    return _job("dqn")
+
+
+@pytest.fixture(scope="module")
+def ddpg_job():
+    return _job("ddpg")
+
+
 def test_one_table_maps_every_layer(registry, job):
     *_, states, run = job
     jax.clear_caches()                     # the call below must compile
@@ -57,6 +68,40 @@ def test_one_table_maps_every_layer(registry, job):
     found = set(tables["jit__fleet_fn"].values())
     assert set(LAYERS) <= found
     assert None in found                   # the key split stays unscoped
+
+
+def test_subscope_table_names_the_knn_beam_and_the_target(registry,
+                                                          ddpg_job):
+    *_, states, run = ddpg_job
+    jax.clear_caches()
+    run(1, states)
+    layers = scope_tables()["jit__fleet_fn"]
+    subs = subscope_tables()["jit__fleet_fn"]
+    assert subs.keys() == layers.keys()
+    pairs = {(layers[n], s) for n, s in subs.items() if s}
+    # the select's beam, the target's beam inside the target, the rest of
+    # the target
+    assert {("agent_select", "knn_projection"),
+            ("agent_update", "critic_target/knn_projection"),
+            ("agent_update", "critic_target")} <= pairs
+    assert {layer for layer, _ in pairs} <= {"agent_select",
+                                             "agent_update", None}
+
+
+def test_dqn_program_carries_no_subscope(registry, job):
+    """The DQN fleet program reaches neither the K-NN beam nor a critic
+    target, so the sub-scopes leave its instructions, and its layer
+    table, as they were."""
+    env, agent, fleet, states, run = job
+    jax.clear_caches()
+    run(1, states)
+    (program, statics, _, _), = registry
+    keys = jax.random.split(jax.random.PRNGKey(3), F)
+    args = prepare_fleet(keys, env, states, None, fleet, None)[:4]
+    text = program.lower(*args, **dict(statics)).compile().as_text()
+    assert not any(name in text for name in SUBSCOPES)
+    assert set(subscope_tables()["jit__fleet_fn"].values()) == {None}
+    assert scope_tables()["jit__fleet_fn"] == spans.parse_hlo(text)[1]
 
 
 def test_same_shapes_record_nothing_new(registry, job):
@@ -156,6 +201,18 @@ def test_layer_is_the_first_scope_on_the_path():
     assert spans.layer_of(path.format("agent_update")) == "agent_update"
     assert spans.layer_of("jit(f)/vmap()/while/body/add") is None
     assert spans.layer_of("jit(f)/env_stepper/add") is None
+
+
+def test_subscope_is_the_path_of_subscopes():
+    body = "jit(f)/vmap()/while/body/agent_update/"
+    assert spans.subscope_of(
+        body + "critic_target/vmap(jit(knn_actions_jax))/knn_projection/"
+        "top_k") == "critic_target/knn_projection"
+    assert spans.subscope_of(body + "critic_target/dot") == "critic_target"
+    assert spans.subscope_of(
+        "jit(f)/agent_select/jit(knn_actions_jax)/knn_projection/eq"
+    ) == "knn_projection"
+    assert spans.subscope_of(body + "transpose(jvp(dot))") is None
 
 
 def test_parse_hlo_reads_names_and_scopes():
