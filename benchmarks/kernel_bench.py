@@ -69,7 +69,7 @@ def bench_knn_projection() -> list[tuple]:
         pj = jnp.asarray(proto)
         f = jax.jit(lambda p, k=k: knn_actions_jax(p, k))
         us = timeit(f, pj)
-        rows.append((f"knn_beam_N{n}M{m}K{k}", us, "jit_in-graph"))
+        rows.append((f"knn_beam_N{n}M{m}K{k}", us, "xla_row_reductions"))
         # Pallas-backed top-2/regret reduction (kernels/knn_topk): timed
         # only where it compiles; the interpreter's time is not the kernel's
         if pj.device.platform != "tpu":

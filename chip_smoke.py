@@ -13,7 +13,7 @@ Everything runs in this one process, through the launcher users call
      policy through the batched ``ControlPlane`` (buffers donated);
   C  one ddpg fleet epoch at the same width with the Pallas K-NN kernel:
      its compiled program holds ``tpu_custom_call`` and selects the same
-     actions as the ``lax.top_k`` path on the same inputs;
+     actions as the XLA path (two row reductions) on the same inputs;
   D  a short ``graph_policy`` fleet over the structural ``dag_shapes``
      env.
 
@@ -135,7 +135,7 @@ def phase_resume_serve(ck_dir, *, app="cq_large", fleet=128, start=100,
 def phase_pallas(*, app="cq_large", fleet=128, seed=0,
                  require_custom_call=True) -> dict:
     """C: one fleet epoch through the Pallas K-NN kernel against the same
-    epoch through ``lax.top_k``, from the same states and keys.  A CPU test
+    epoch through the XLA row top-2, from the same states and keys.  A CPU test
     swaps in the interpreted kernel and passes
     ``require_custom_call=False``."""
     import jax
@@ -146,7 +146,7 @@ def phase_pallas(*, app="cq_large", fleet=128, seed=0,
     env = build_env(app)
     key = jax.random.PRNGKey(seed)
     runs = {}
-    for name, pallas in (("top_k", False), ("pallas", True)):
+    for name, pallas in (("xla", False), ("pallas", True)):
         agent = make_agent("ddpg", env, use_pallas_knn=pallas)
         keys = jax.random.split(jax.random.fold_in(key, 2), fleet)
         keys, states, env_states, params, _, axes, _ = prepare_fleet(
@@ -161,12 +161,12 @@ def phase_pallas(*, app="cq_large", fleet=128, seed=0,
     kernel_in = runs["pallas"][0]
     if require_custom_call and not kernel_in:
         raise SmokeError("no tpu_custom_call in the Pallas fleet program")
-    if not np.array_equal(runs["pallas"][1], runs["top_k"][1]):
-        raise SmokeError("Pallas and lax.top_k selected different actions")
+    if not np.array_equal(runs["pallas"][1], runs["xla"][1]):
+        raise SmokeError("Pallas and XLA K-NN selected different actions")
     # the two programs fuse differently, so rewards may differ in the
     # last bits even for equal actions
-    if not np.allclose(runs["pallas"][2], runs["top_k"][2], rtol=1e-5):
-        raise SmokeError("Pallas and lax.top_k rewards differ")
+    if not np.allclose(runs["pallas"][2], runs["xla"][2], rtol=1e-5):
+        raise SmokeError("Pallas and XLA K-NN rewards differ")
     return {"tpu_custom_call": kernel_in, "lanes": fleet,
             "actions_equal": True}
 

@@ -224,8 +224,8 @@ def test_model_based_no_retrace_across_calls():
 
 def test_ddpg_select_pallas_knn_matches_default(small_env,
                                                 interpreted_knn_kernel):
-    """The Pallas-backed K-NN projection is a drop-in for the lax.top_k
-    beam inside the DDPG select path (interpret mode on CPU)."""
+    """The Pallas-backed K-NN projection is a drop-in for the XLA row
+    top-2 inside the DDPG select path (interpret mode on CPU)."""
     env = small_env
     kw = dict(n_executors=env.N, n_machines=env.M,
               state_dim=env.state_dim, k_nn=4)

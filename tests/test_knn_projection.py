@@ -80,7 +80,7 @@ def test_jax_beam_contains_exact_1nn():
 def test_pallas_beam_matches_xla_beam_exactly():
     """knn_actions_jax(use_pallas=True) routes the top-2/regret reduction
     through the kernels/knn_topk Pallas kernel (interpret mode, asked for
-    explicitly on CPU) and must match the lax.top_k beam bit for bit."""
+    explicitly on CPU) and must match the XLA beam bit for bit."""
     for seed, (n, m, k) in [(0, (40, 10, 8)), (1, (25, 6, 6)),
                             (2, (7, 3, 4)), (3, (100, 10, 16))]:
         proto = jax.random.uniform(jax.random.PRNGKey(seed), (n, m))
@@ -88,6 +88,82 @@ def test_pallas_beam_matches_xla_beam_exactly():
         pallas = np.asarray(knn_actions_jax(proto, k, use_pallas=True,
                                             interpret=True))
         np.testing.assert_array_equal(pallas, beam)
+
+
+def topk_beam(proto, k, pair_pool=8, triple_pool=4):
+    """The beam as three ``lax.top_k`` calls and a row scatter: the plain
+    formulation ``knn_actions_jax`` must reproduce bit for bit."""
+    n, m = proto.shape
+    top2_vals, top2_idx = jax.lax.top_k(proto, 2)
+    best_col, second_col = top2_idx[:, 0], top2_idx[:, 1]
+    flip_regret = 2.0 * (top2_vals[:, 0] - top2_vals[:, 1])
+    pool = min(max(pair_pool, triple_pool, k), n)
+    cheap_cost, cheap_rows = jax.lax.top_k(-flip_regret, pool)
+    cheap_cost = -cheap_cost
+    sets = [()] + [(i,) for i in range(pool)]
+    p, t = min(pair_pool, pool), min(triple_pool, pool)
+    sets += [(i, j) for i in range(p) for j in range(i + 1, p)]
+    sets += [(i, j, l) for i in range(t) for j in range(i + 1, t)
+             for l in range(j + 1, t)]
+    masks, costs = [], []
+    for rows in sets:
+        mask = jnp.zeros((pool,), jnp.bool_)
+        cost = jnp.zeros(()) if not rows else cheap_cost[rows[0]]
+        for i in rows:
+            mask = mask.at[i].set(True)
+        for i in rows[1:]:
+            cost = cost + cheap_cost[i]
+        masks.append(mask)
+        costs.append(cost)
+    cand_masks, cand_costs = jnp.stack(masks), jnp.stack(costs)
+    kk = min(k, len(sets))
+    _, sel = jax.lax.top_k(-cand_costs, kk)
+
+    def build(mask_row):
+        flip = jnp.zeros((n,), jnp.bool_).at[cheap_rows].set(mask_row)
+        return jax.nn.one_hot(jnp.where(flip, second_col, best_col), m,
+                              dtype=jnp.float32)
+
+    actions = jax.vmap(build)(cand_masks[sel])
+    return jnp.concatenate(
+        [actions, jnp.repeat(actions[-1:], k - kk, axis=0)], axis=0)
+
+
+def _protos(kind, n, m, batch=6, seed=0):
+    """``batch`` protos [batch, n, m] of one kind: continuous, rounded to
+    quarters (ties, and −0 beside +0), with ±inf entries and rows, or with
+    NaN entries and rows of either sign."""
+    rng = np.random.default_rng([seed, n, m])
+    x = rng.uniform(-1.0, 1.0, (batch, n, m)).astype(np.float32)
+
+    def spots(count):
+        return (np.arange(batch)[:, None],
+                rng.integers(n, size=(batch, count)),
+                rng.integers(m, size=(batch, count)))
+    if kind == "quarters":
+        x = np.round(x * 4) / 4
+    elif kind == "inf":
+        x[spots(3)], x[spots(2)] = np.inf, -np.inf
+        x[:, 0], x[:, -1] = np.inf, -np.inf
+    elif kind == "nan":
+        x[spots(3)], x[spots(2)] = np.nan, -np.nan
+        x[:, 1], x[:, -2] = np.nan, -np.nan
+    return jnp.asarray(x)
+
+
+@pytest.mark.parametrize("kind", ["continuous", "quarters", "inf", "nan"])
+@pytest.mark.parametrize("n,m,k", [(7, 3, 4), (25, 6, 6), (40, 10, 8),
+                                   (100, 10, 12), (100, 10, 256)])
+def test_beam_matches_top_k_formulation_bit_for_bit(n, m, k, kind):
+    """The rank-based beam returns the same candidates, in the same order,
+    as the ``lax.top_k`` formulation: ties to the lower index, floats in
+    IEEE total order (+NaN highest, −NaN lowest, −0 below +0).  (100, 10,
+    256) is the deploy-time K: every row is in the pool, 133 candidates."""
+    protos = _protos(kind, n, m)
+    want = jax.jit(jax.vmap(lambda p: topk_beam(p, k)))(protos)
+    got = jax.vmap(lambda p: knn_actions_jax(p, k))(protos)
+    assert got.shape == (protos.shape[0], k, n, m)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_nearest_assignment_is_row_argmax():

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.knn_projection import knn_actions_jax
 from repro.kernels.knn_topk import row_top2_regret
 
 
@@ -56,6 +57,19 @@ def test_row_top2_regret_compiles_for_v5e(one_chip, shape, vmaps):
     assert "tpu_custom_call" in text
 
 
+def test_knn_beam_compiles_without_sorts_or_scatters_for_v5e(one_chip):
+    """The K-NN beam at the DDPG target's width (128 lanes × 32 samples,
+    N=100, M=10, K=12) picks its rows and candidates by comparison ranks:
+    the chip's program holds no sort (a TPU top-k sorts every element) and
+    no scatter."""
+    f = jax.vmap(jax.vmap(lambda p: knn_actions_jax(p, 12)))
+    x = jax.ShapeDtypeStruct((128, 32, 100, 10), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(f).lower(x).compile().as_text()
+    assert " sort(" not in text
+    assert " scatter(" not in text
+
+
 def _compile_cq_large_fleet_epoch(agent_name, sharding):
     """The fused epoch program (select → env.step → store → update) of a
     128-lane cq_large fleet, compiled for ``sharding``'s device."""
@@ -78,8 +92,9 @@ def _compile_cq_large_fleet_epoch(agent_name, sharding):
 
 def test_cq_large_ddpg_fleet_epoch_compiles_for_one_v5e(one_chip):
     """The fused epoch program of a 128-lane cq_large ddpg fleet fits one
-    chip."""
+    chip, and sorts nothing: its K-NN beams rank by comparisons."""
     compiled = _compile_cq_large_fleet_epoch("ddpg", one_chip)
+    assert " sort(" not in compiled.as_text()
     mem = compiled.memory_analysis()
     print(f"cq_large ddpg fleet F=128 epoch on one v5e: {mem}")
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
